@@ -140,8 +140,11 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
                 optimizer: str = "adam",
                 feed_arrivals: Optional[bool] = None,
                 round_impl: str = "dense",
-                ledger=None):
-    """Returns (state, cfg, history dict).
+                ledger=None,
+                on_round=None):
+    """Returns (state, cfg, history dict).  ``history["round_fn"]`` is the
+    jitted round that ran (lower it to see what it compiled to);
+    ``on_round(t, state, metrics)`` is :meth:`FederatedRun.run`'s hook.
 
     ``schedule`` (a sparse :class:`repro.core.schedule.Schedule`, e.g.
     from ``build_schedule``) feeds the external event-driven schedule —
@@ -215,12 +218,13 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
         round_kwargs=_legacy_round_kwargs(schedule, active_masks, staleness,
                                           rounds, fed.n_clients))
     state, hist = run.run(
-        state, batch_fn, key, collect=collect,
+        state, batch_fn, key, collect=collect, on_round=on_round,
         derive={
             "eps_all": lambda s, m: np.asarray(s.eps).copy(),
             "rmse": lambda s, m: eval_fed_state(s, cfg, test, scalers)[0],
             "mae": lambda s, m: eval_fed_state(s, cfg, test, scalers)[1],
         })
+    hist["round_fn"] = step
     return state, cfg, hist
 
 

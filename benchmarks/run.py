@@ -19,6 +19,7 @@ from benchmarks import (fig3_privacy_level, fig456_async_efficiency,
                         kernel_bench, roofline_table, table1_prediction,
                         table23_privacy_budget, table4_byzantine,
                         theorem1_convergence)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "table1": table1_prediction.main,
@@ -44,6 +45,7 @@ def main() -> int:
                     default=int(os.environ.get("BENCH_ROUNDS", "150")))
     args = ap.parse_args()
 
+    enable_compile_cache()
     names = [n.strip() for n in args.only.split(",") if n.strip()] or \
         list(SUITES)
     print("name,us_per_call,derived")

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import FedConfig, MLP_H1
 from repro.core import bafdp, init_fed_state
 from repro.core.async_engine import DelayModel
@@ -59,6 +60,7 @@ def main():
     ap.add_argument("--server", default="quorum",
                     choices=["quorum", "fedbuff", "sync"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = MLP_H1
     fed = FedConfig(n_clients=args.clients, byzantine_frac=args.byzantine,

@@ -130,7 +130,7 @@ def f64_promoting_step(z: jax.Array) -> jax.Array:
 
 
 def _trace_f64_broken():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return jax.make_jaxpr(f64_promoting_step)(
             jax.ShapeDtypeStruct((D_FIX,), jnp.float64))
 

@@ -285,7 +285,7 @@ class AccumulationDtypeRule(Rule):
 class RngDisciplineRule(Rule):
     """Every key consumption must be a distinct derivation.
 
-    The pass value-numbers the jaxpr (inlining through ``pjit``-style call
+    The pass value-numbers the jaxpr (inlining through ``jit``-style call
     primitives, conservative fresh values at ``scan``/``while``/``cond``
     boundaries, so a key carried into a loop is a fresh key per
     iteration), then groups the PRNG-consuming equations —
@@ -308,9 +308,8 @@ class RngDisciplineRule(Rule):
             "(key, leaf, client-id) convention of byzantine.corrupt)")
 
     CALL_PRIMS = frozenset((
-        "pjit", "closed_call", "core_call", "xla_call", "remat2",
-        "checkpoint", "custom_jvp_call", "custom_vjp_call",
-        "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
+        "jit", "closed_call", "call", "remat2", "custom_jvp_call",
+        "custom_vjp_call", "custom_jvp_call_jaxpr",
     ))
     OPAQUE_PRIMS = frozenset(("scan", "while", "cond"))
     CONSUME_PRIMS = frozenset(("random_bits", "random_split",

@@ -8,7 +8,7 @@ one invariant.  Copies rot: the PR-4 int8-accumulator wrap and the PR-6
 padding-polluted ``alie`` statistics both shipped before their walker
 existed.  Everything here is pure structural traversal — no rule logic.
 
-The traversal carries an *equation path* (e.g. ``pjit(_normal)/scan/body``)
+The traversal carries an *equation path* (e.g. ``jit(_normal)/scan/body``)
 so a finding deep inside a scanned sub-jaxpr is diagnosable without
 re-deriving where it came from.
 """
@@ -16,14 +16,14 @@ from __future__ import annotations
 
 from typing import Any, Iterator, List, Tuple
 
-from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def subjaxprs(eqn) -> Iterator[Tuple[str, Jaxpr]]:
     """All sub-jaxprs referenced by ``eqn``'s params, as (label, jaxpr).
 
     Handles every higher-order primitive layout jax uses: a bare ``Jaxpr``
-    or ``ClosedJaxpr`` param (``pjit``, ``scan``, ``while``, ``remat``,
+    or ``ClosedJaxpr`` param (``jit``, ``scan``, ``while``, ``remat``,
     custom derivatives) and tuples/lists of them (``cond`` branches).  The
     label names the param (plus the branch index for sequences) so paths
     stay readable.
@@ -47,7 +47,7 @@ def _label(eqn) -> str:
 
 
 def iter_eqns(jaxpr: Jaxpr) -> Iterator[Any]:
-    """All eqns of ``jaxpr``, recursing into sub-jaxprs (pjit, scan, while,
+    """All eqns of ``jaxpr``, recursing into sub-jaxprs (jit, scan, while,
     cond, ...) depth-first.  Accepts a ``Jaxpr`` or ``ClosedJaxpr``."""
     for eqn, _ in iter_eqns_with_path(jaxpr):
         yield eqn

@@ -153,7 +153,12 @@ def encode_dual_message(phi: jnp.ndarray) -> DualMessage:
     """
     phif = phi.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(phif), axis=-1)
-    scale = jnp.where(absmax > 0.0, absmax / DUAL_INT8_LEVELS, 1.0)
+    # multiply by the f32 step 1/127 rather than divide by 127: XLA rewrites
+    # a division by a constant into this multiply inside jit but not in an
+    # eager op, and the two round differently — the scale must not depend
+    # on where the encode is traced
+    scale = jnp.where(absmax > 0.0,
+                      absmax * jnp.float32(1.0 / DUAL_INT8_LEVELS), 1.0)
     # |phi|/scale <= 127 mathematically, but the f32-rounded scale can sit
     # a ulp low — clip so the int8 cast can never wrap at the extremes
     q = jnp.clip(jnp.round(phif / scale[..., None]),

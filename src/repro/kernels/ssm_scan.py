@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 DEFAULT_CHUNK = 128
 DEFAULT_BD = 256
@@ -62,7 +61,7 @@ def ssm_scan(a: jnp.ndarray, b: jnp.ndarray, *, chunk: int = DEFAULT_CHUNK,
                                lambda ib, idd, ic: (ib, ic, idd, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, D, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

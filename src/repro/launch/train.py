@@ -36,7 +36,9 @@ def main() -> int:
 
     if args.dry:
         # delegate to the dry-run module (which must own process start-up
-        # because of the XLA device-count flag)
+        # because of the XLA device-count flag).  The child is spawned
+        # before this process imports JAX: a process that has touched JAX
+        # holds the chip, and a child that needs it would then fail or hang.
         import os
         import subprocess
         import sys
@@ -51,6 +53,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     from repro.checkpoint import Checkpointer
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import INPUT_SHAPES, get_arch, reduce_for_smoke
     from repro.core.fed_state import init_fed_state
     from repro.data.tokens import lm_batch
@@ -59,6 +62,7 @@ def main() -> int:
     from repro.launch.mesh import make_host_mesh
     from repro.models import transformer as tr
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     shape = INPUT_SHAPES[args.shape]
     if args.smoke:

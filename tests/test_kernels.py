@@ -8,8 +8,10 @@ import pytest
 from repro.kernels import ops, ref
 
 
+# C = 300 is not a multiple of the kernels' client tile (256 f32 rows), so
+# the last client tile is partial and its rows past C must add zero
 @pytest.mark.parametrize("D", [128, 1024, 5000, 8193])
-@pytest.mark.parametrize("C", [2, 16])
+@pytest.mark.parametrize("C", [2, 16, 300])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_sign_agg(D, C, dtype):
     key = jax.random.PRNGKey(D + C)
@@ -25,11 +27,14 @@ def test_sign_agg(D, C, dtype):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("n_pad", [0, 7])
 @pytest.mark.parametrize("D", [128, 1024, 5000, 8193])
-@pytest.mark.parametrize("C", [2, 16])
+@pytest.mark.parametrize("C", [2, 16, 300])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sign_agg_weighted(D, C, dtype):
-    """Pallas staleness-weighted sign reduction vs the jnp oracle."""
+def test_sign_agg_weighted(D, C, dtype, n_pad):
+    """Pallas staleness-weighted sign reduction vs the jnp oracle.  With
+    ``n_pad`` the block carries extra rows at weight 0 (the sparse round's
+    padding), which must add exactly zero while the divisor stays C."""
     key = jax.random.PRNGKey(D * C)
     z = jax.random.normal(key, (D,), dtype)
     W = jax.random.normal(jax.random.fold_in(key, 1), (C, D), dtype)
@@ -37,8 +42,14 @@ def test_sign_agg_weighted(D, C, dtype):
            ).astype(dtype)
     sw = jax.random.uniform(jax.random.fold_in(key, 3), (C,),
                             minval=0.05, maxval=1.0)
-    got = ops.sign_agg_weighted(z, W, phi, sw, 0.005, 0.01,
-                                impl="interpret")
+    if n_pad:
+        W_pad = jnp.concatenate([W, jnp.full((n_pad, D), 1e9, dtype)])
+        sw_pad = jnp.concatenate([sw, jnp.zeros((n_pad,))])
+        got = ops.sign_consensus(z, W_pad, phi, sw_pad, 0.005, 0.01,
+                                 impl="interpret", n_total=C)
+    else:
+        got = ops.sign_agg_weighted(z, W, phi, sw, 0.005, 0.01,
+                                    impl="interpret")
     want = ref.sign_agg_weighted_ref(z, W, phi, sw, 0.005, 0.01)
     tol = 1e-6 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -160,9 +171,12 @@ def _consensus_problem(D=1500, C=12, seed=0):
     return z, W, phi
 
 
+# C = 1100 leaves a partial last client tile for both wire formats
+# (256 f32 rows, 1024 int8 rows per tile)
+@pytest.mark.parametrize("C", [12, 1100])
 @pytest.mark.parametrize("decay", ["constant", "hinge", "poly"])
 @pytest.mark.parametrize("message", ["f32", "int8"])
-def test_sign_consensus_dispatch_parity(decay, message):
+def test_sign_consensus_dispatch_parity(decay, message, C):
     """Fused (interpret) vs XLA vs the ref oracles, for every
     staleness_decay mode and both wire formats: one dispatch, one result.
     The int8 wire format is lossless for sign messages, so the only
@@ -172,8 +186,7 @@ def test_sign_consensus_dispatch_parity(decay, message):
     from repro.configs import FedConfig
     from repro.core.bafdp import staleness_weights
 
-    z, W, phi = _consensus_problem()
-    C = W.shape[0]
+    z, W, phi = _consensus_problem(C=C)
     stale = jnp.arange(C, dtype=jnp.float32)
     weights = None if decay == "constant" else staleness_weights(
         stale, FedConfig(staleness_decay=decay))
