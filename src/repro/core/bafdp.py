@@ -734,59 +734,63 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
             "staleness_compensation='taylor' needs FedState.comp — "
             "init_fed_state with the same FedConfig")
     C = byz_mask.shape[0]
-    idx = jnp.asarray(idx).astype(jnp.int32)
-    (S,) = idx.shape
-    w_row = jnp.ones((S,), jnp.float32) if weight is None \
-        else jnp.asarray(weight).astype(jnp.float32)
-    stale_row = jnp.zeros((S,), jnp.float32) if stale is None \
-        else jnp.asarray(stale).astype(jnp.float32)
-    # normalize padding (out-of-range id OR zero weight; negative ids
-    # would otherwise clip-gather client 0 into the consensus with full
-    # weight while their write-back is dropped), then canonicalize to
-    # ascending client id: the stable sort puts padding last, preserves
-    # FedBuff arrival order between duplicate ids, and makes the consensus
-    # fold visit clients in the dense round's ascending order — so row
-    # order in idx can never change the result
-    w_row = jnp.where((idx < 0) | (idx >= C), 0.0, w_row)
-    idx = jnp.where(w_row > 0.0, idx, C)
-    order = jnp.argsort(idx, stable=True)
-    idx, stale_row, w_row = idx[order], stale_row[order], w_row[order]
-    gid = jnp.minimum(idx, C - 1)        # clipped gather index for padding
-    # deterministic left-fold write-back: only each client's LAST delivery
-    # (arrival order; rows are stably sorted) writes state.  With
-    # per-client batches duplicate rows are identical anyway, but
-    # pre-gathered (batch_gathered=True) deliveries may carry distinct
-    # data — and XLA's scatter order for repeated indices is unspecified,
-    # so last-wins must be enforced, not assumed.
-    is_last = jnp.concatenate([idx[:-1] != idx[1:],
-                               jnp.ones((1,), bool)]) if S > 1 \
-        else jnp.ones((1,), bool)
-    write_idx = jnp.where(is_last, idx, C)
+    # each stage of the round runs under a named scope, which the
+    # compiled program keeps in its ops' op_name metadata for a
+    # device trace to read (gather_clients and scatter_clients
+    # carry their own); a scope changes no op
+    with jax.named_scope("bafdp.gather"):
+        idx = jnp.asarray(idx).astype(jnp.int32)
+        (S,) = idx.shape
+        w_row = jnp.ones((S,), jnp.float32) if weight is None \
+            else jnp.asarray(weight).astype(jnp.float32)
+        stale_row = jnp.zeros((S,), jnp.float32) if stale is None \
+            else jnp.asarray(stale).astype(jnp.float32)
+        # normalize padding (out-of-range id OR zero weight; negative ids
+        # would otherwise clip-gather client 0 into the consensus with full
+        # weight while their write-back is dropped), then canonicalize to
+        # ascending client id: the stable sort puts padding last, preserves
+        # FedBuff arrival order between duplicate ids, and makes the consensus
+        # fold visit clients in the dense round's ascending order — so row
+        # order in idx can never change the result
+        w_row = jnp.where((idx < 0) | (idx >= C), 0.0, w_row)
+        idx = jnp.where(w_row > 0.0, idx, C)
+        order = jnp.argsort(idx, stable=True)
+        idx, stale_row, w_row = idx[order], stale_row[order], w_row[order]
+        gid = jnp.minimum(idx, C - 1)        # clipped gather index for padding
+        # deterministic left-fold write-back: only each client's LAST delivery
+        # (arrival order; rows are stably sorted) writes state.  With
+        # per-client batches duplicate rows are identical anyway, but
+        # pre-gathered (batch_gathered=True) deliveries may carry distinct
+        # data — and XLA's scatter order for repeated indices is unspecified,
+        # so last-wins must be enforced, not assumed.
+        is_last = jnp.concatenate([idx[:-1] != idx[1:],
+                                   jnp.ones((1,), bool)]) if S > 1 \
+            else jnp.ones((1,), bool)
+        write_idx = jnp.where(is_last, idx, C)
 
-    t = state.t
-    stale_v = stale_row
-    s_w = staleness_weights(stale_v, fed) * w_row          # (S,) decay+mask
-    tau_g = jnp.take(state.tau, gid, axis=0, mode="clip")
-    s_w_dual = staleness_weights((t - tau_g).astype(jnp.float32), fed)
+        t = state.t
+        stale_v = stale_row
+        s_w = staleness_weights(stale_v, fed) * w_row      # (S,) decay+mask
+        tau_g = jnp.take(state.tau, gid, axis=0, mode="clip")
+        s_w_dual = staleness_weights((t - tau_g).astype(jnp.float32), fed)
 
-    k_act, k_noise, k_byz = jax.random.split(key, 3)
-    del k_act  # the active set IS idx; split kept so the noise/byz key
-    #            stream matches the dense round bit-for-bit
-    noise_keys = jax.random.split(k_noise, C)[gid]         # O(C) keys, (C,)
-    byz_g = jnp.take(byz_mask, gid, axis=0, mode="clip") & (w_row > 0.0)
+        k_act, k_noise, k_byz = jax.random.split(key, 3)
+        del k_act  # the active set IS idx; split kept so the noise/byz key
+        #            stream matches the dense round bit-for-bit
+        noise_keys = jax.random.split(k_noise, C)[gid]     # O(C) keys, (C,)
+        byz_g = jnp.take(byz_mask, gid, axis=0, mode="clip") & (w_row > 0.0)
 
     # ---------------- gather the round's S rows of every big leaf ---------
     W_g = gather_clients(state.W, gid)
     zl_g = gather_clients(state.z_local, gid)
     phi_g = gather_clients(state.phi, gid)
-    eps_g = jnp.take(state.eps, gid, axis=0, mode="clip")
-    lam_g = jnp.take(state.lam, gid, axis=0, mode="clip")
+    eps_g = gather_clients(state.eps, gid)
+    lam_g = gather_clients(state.lam, gid)
     opt_g = None
     if state.opt is not None:
         opt_g = {"m": gather_clients(state.opt["m"], gid),
                  "v": gather_clients(state.opt["v"], gid),
-                 "count": jnp.take(state.opt["count"], gid, axis=0,
-                                   mode="clip")}
+                 "count": gather_clients(state.opt["count"], gid)}
     comp_g = gather_clients(state.comp, gid) if state.comp is not None \
         else None
 
@@ -810,22 +814,24 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
         # them along with the canonicalized (sorted) rows
         return jnp.take(l, order, axis=0)
 
-    batch_g = jax.tree.map(pick_batch, batch)
+    with jax.named_scope("bafdp.gather"):
+        batch_g = jax.tree.map(pick_batch, batch)
     # data-poisoning attacks corrupt the malicious rows' batches before the
     # local step (row-local + deterministic, so dense/sparse stay identical)
-    batch_g = byz_lib.poison_batch(fed.attack, batch_g, byz_g,
-                                   shift=fed.traffic_shift_steps)
+    with jax.named_scope("bafdp.attack"):
+        batch_g = byz_lib.poison_batch(fed.attack, batch_g, byz_g,
+                                       shift=fed.traffic_shift_steps)
 
     # ---------------- Step 1 on the gathered block ------------------------
-    (W_prop, opt_prop, comp_prop, eps_prop, loss_i, g_i, G_i,
-     full_grad) = _client_block_updates(
-        W_g, zl_g, phi_g, eps_g, lam_g, opt_g, comp_g, batch_g, noise_keys,
-        jnp.ones((S,), jnp.int32), local_loss=local_loss, fed=fed, c3=c3,
-        n_samples=n_samples, d_dim=d_dim, taylor=taylor)
+    with jax.named_scope("bafdp.local_step"):
+        (W_prop, opt_prop, comp_prop, eps_prop, loss_i, g_i, G_i,
+         full_grad) = _client_block_updates(
+            W_g, zl_g, phi_g, eps_g, lam_g, opt_g, comp_g, batch_g, noise_keys,
+            jnp.ones((S,), jnp.int32), local_loss=local_loss, fed=fed, c3=c3,
+            n_samples=n_samples, d_dim=d_dim, taylor=taylor)
 
     # ---------------- scatter state writes back ---------------------------
-    tau_new = state.tau.at[write_idx].set(t.astype(state.tau.dtype),
-                                          mode="drop")
+    tau_new = scatter_clients(state.tau, write_idx, t)
     W_new = scatter_clients(state.W, write_idx, W_prop)
     new_opt = state.opt
     if fed.omega_optimizer == "adam" and state.opt is not None:
@@ -833,14 +839,14 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
                                         opt_prop["m"]),
                    "v": scatter_clients(state.opt["v"], write_idx,
                                         opt_prop["v"]),
-                   "count": state.opt["count"].at[write_idx].set(
-                       opt_prop["count"], mode="drop")}
+                   "count": scatter_clients(state.opt["count"], write_idx,
+                                            opt_prop["count"])}
     new_comp = state.comp
     comp_blocks = comp_g
     if taylor:
         new_comp = scatter_clients(state.comp, write_idx, comp_prop)
         comp_blocks = comp_prop
-    eps_new = state.eps.at[write_idx].set(eps_prop, mode="drop")
+    eps_new = scatter_clients(state.eps, write_idx, eps_prop)
 
     wsum_act = jnp.maximum(jnp.sum(w_row), 1.0)
 
@@ -877,30 +883,31 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
     # CLIENT id (padding rows draw client C-1's stream but byz_g already
     # zeroes them) and weight=w_row masks alie's cross-client statistics —
     # both are what make the attack width-independent (dense bit-parity)
-    W_sent = byz_lib.apply_attack(fed.attack, k_byz, W_prop, byz_g,
-                                  scale=fed.attack_scale, client_ids=gid,
-                                  weight=w_row)
-    comp_norm = jnp.zeros(())
-    W_srv = W_sent
-    if taylor:
-        W_srv = compensate_stale(W_sent, comp_blocks, stale_v, fed)
-        # delivered-weighted per-element movement: padding / zero-weight
-        # rows drop out, so the statistic is block-width-invariant — the
-        # full-width masked block and the gathered block report the same
-        # value (the dense "all" scope keeps its fleet-wide formula)
-        per_row = jnp.zeros((S,), jnp.float32)
-        for a, b in zip(jax.tree.leaves(W_srv), jax.tree.leaves(W_sent)):
-            per_row = per_row + jnp.sum(
-                jnp.abs(a - b.astype(jnp.float32)).reshape(S, -1), axis=1)
-        den = float(sum(l.size for l in jax.tree.leaves(W_sent))) / S
-        comp_norm = jnp.where(
-            do_consensus,
-            jnp.sum(per_row * w_row) / (wsum_act * max(den, 1.0)), 0.0)
+    with jax.named_scope("bafdp.attack"):
+        W_sent = byz_lib.apply_attack(fed.attack, k_byz, W_prop, byz_g,
+                                      scale=fed.attack_scale, client_ids=gid,
+                                      weight=w_row)
+        comp_norm = jnp.zeros(())
+        W_srv = W_sent
+        if taylor:
+            W_srv = compensate_stale(W_sent, comp_blocks, stale_v, fed)
+            # delivered-weighted per-element movement: padding / zero-weight
+            # rows drop out, so the statistic is block-width-invariant — the
+            # full-width masked block and the gathered block report the same
+            # value (the dense "all" scope keeps its fleet-wide formula)
+            per_row = jnp.zeros((S,), jnp.float32)
+            for a, b in zip(jax.tree.leaves(W_srv), jax.tree.leaves(W_sent)):
+                per_row = per_row + jnp.sum(
+                    jnp.abs(a - b.astype(jnp.float32)).reshape(S, -1), axis=1)
+            den = float(sum(l.size for l in jax.tree.leaves(W_sent))) / S
+            comp_norm = jnp.where(
+                do_consensus,
+                jnp.sum(per_row * w_row) / (wsum_act * max(den, 1.0)), 0.0)
 
-    # Byzantine-robust pre-aggregation over the S delivered messages
-    # (weight-aware: padding rows are invisible to the robust statistics)
-    if fed.robust_consensus != "none":
-        W_srv = _robust_broadcast(W_srv, w_row, state.z, fed)
+        # Byzantine-robust pre-aggregation over the S delivered messages
+        # (weight-aware: padding rows are invisible to the robust statistics)
+        if fed.robust_consensus != "none":
+            W_srv = _robust_broadcast(W_srv, w_row, state.z, fed)
 
     if fed.fedbuff_lr_norm:
         # the padded row carries the realized K natively (duplicate
@@ -939,34 +946,35 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
                      ).astype(z_l.dtype)
         return jnp.where(do_consensus, z_upd, zf).reshape(z_l.shape)
 
-    z_new = jax.tree.map(z_step, state.z, W_srv, phi_g)
+    with jax.named_scope("bafdp.fold"):
+        z_new = jax.tree.map(z_step, state.z, W_srv, phi_g)
 
-    a1_t = reg_decay(fed.alpha_lambda, t, fed.reg_decay_pow)
-    lam_new = state.lam + fed.alpha_lambda * (
-        (eps_new - fed.privacy_budget_a) - a1_t * state.lam)
-    lam_new = jnp.maximum(lam_new, 0.0)
+    with jax.named_scope("bafdp.dual"):
+        a1_t = reg_decay(fed.alpha_lambda, t, fed.reg_decay_pow)
+        lam_new = state.lam + fed.alpha_lambda * (
+            (eps_new - fed.privacy_budget_a) - a1_t * state.lam)
+        lam_new = jnp.maximum(lam_new, 0.0)
 
-    # ---------------- Step 3: delivered clients update phi, sync z --------
-    a2_t = reg_decay(fed.alpha_phi, t, fed.reg_decay_pow)
-    W_dual = W_prop
-    if taylor:
-        lag = jnp.maximum((t - tau_g).astype(jnp.float32) - 1.0, 0.0)
-        W_dual = compensate_stale(W_prop, comp_blocks, lag, fed)
+        # ---------------- Step 3: delivered clients update phi, sync z ----
+        a2_t = reg_decay(fed.alpha_phi, t, fed.reg_decay_pow)
+        W_dual = W_prop
+        if taylor:
+            lag = jnp.maximum((t - tau_g).astype(jnp.float32) - 1.0, 0.0)
+            W_dual = compensate_stale(W_prop, comp_blocks, lag, fed)
 
-    def phi_step(phi_l, z_l, w_l):
-        upd = (z_l[None].astype(jnp.float32) - w_l.astype(jnp.float32)) \
-            - a2_t * phi_l.astype(jnp.float32)
-        if fed.staleness_decay != "constant":
-            upd = upd * s_w_dual.reshape((-1,) + (1,) * (phi_l.ndim - 1))
-        return phi_l.astype(jnp.float32) + fed.alpha_phi * upd
+        def phi_step(phi_l, z_l, w_l):
+            upd = (z_l[None].astype(jnp.float32) - w_l.astype(jnp.float32)) \
+                - a2_t * phi_l.astype(jnp.float32)
+            if fed.staleness_decay != "constant":
+                upd = upd * s_w_dual.reshape((-1,) + (1,) * (phi_l.ndim - 1))
+            return phi_l.astype(jnp.float32) + fed.alpha_phi * upd
 
-    phi_blocks = jax.tree.map(phi_step, phi_g, z_new, W_dual)
+        phi_blocks = jax.tree.map(phi_step, phi_g, z_new, W_dual)
+        zl_blocks = jax.tree.map(
+            lambda zl_l, z_l: jnp.broadcast_to(
+                z_l[None].astype(jnp.float32), (S,) + z_l.shape),
+            zl_g, z_new)
     phi_new = scatter_clients(state.phi, write_idx, phi_blocks)
-
-    zl_blocks = jax.tree.map(
-        lambda zl_l, z_l: jnp.broadcast_to(
-            z_l[None].astype(jnp.float32), (S,) + z_l.shape),
-        zl_g, z_new)
     z_local_new = scatter_clients(state.z_local, write_idx, zl_blocks)
 
     new_state = FedState(W=W_new, z=z_new, z_local=z_local_new, phi=phi_new,
@@ -988,20 +996,21 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
     # identically valued between the dense active-scope round (which runs
     # THIS function over the full-width masked block) and the gathered
     # sparse round, so dense-vs-sparse histories compare key-for-key.
-    metrics = {
-        "loss": jnp.sum(loss_i * w_row) / wsum_act,
-        "data_loss": jnp.sum(g_i * w_row) / wsum_act,
-        "lipschitz_block": jnp.sum(G_i * w_row) / wsum_act,
-        "eps_mean": jnp.mean(eps_new),
-        "lambda_mean": jnp.mean(lam_new),
-        "consensus_gap_block": subset_gap(),   # over the delivered block
-        "n_active": jnp.sum(w_row),
-        "staleness_mean_block": jnp.sum(stale_v * w_row) / wsum_act,
-        "staleness_weight_mean_block": jnp.sum(
-            staleness_weights(stale_v, fed) * w_row) / wsum_act,
-        "compensation_norm_block": comp_norm,
-        "metrics_k": wsum_act,
-    }
+    with jax.named_scope("bafdp.metrics"):
+        metrics = {
+            "loss": jnp.sum(loss_i * w_row) / wsum_act,
+            "data_loss": jnp.sum(g_i * w_row) / wsum_act,
+            "lipschitz_block": jnp.sum(G_i * w_row) / wsum_act,
+            "eps_mean": jnp.mean(eps_new),
+            "lambda_mean": jnp.mean(lam_new),
+            "consensus_gap_block": subset_gap(),   # over the delivered block
+            "n_active": jnp.sum(w_row),
+            "staleness_mean_block": jnp.sum(stale_v * w_row) / wsum_act,
+            "staleness_weight_mean_block": jnp.sum(
+                staleness_weights(stale_v, fed) * w_row) / wsum_act,
+            "compensation_norm_block": comp_norm,
+            "metrics_k": wsum_act,
+        }
     return new_state, metrics
 
 

@@ -11,6 +11,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.configs.base import FedConfig
 
 
@@ -35,7 +36,12 @@ class FedState(NamedTuple):
 def init_fed_state(key, init_params: Callable[[Any], Any],
                    fed: FedConfig, n_clients: Optional[int] = None) -> FedState:
     """``init_params(key) -> params`` builds one client's model."""
-    C = n_clients or fed.n_clients
+    with tracing.span("fed.init_state"):
+        return _init_fed_state(key, init_params, fed,
+                               n_clients or fed.n_clients)
+
+
+def _init_fed_state(key, init_params, fed: FedConfig, C: int) -> FedState:
     keys = jax.random.split(key, C)
     W = jax.vmap(init_params)(keys)
     z = jax.tree.map(lambda l: l[0], W)
@@ -71,10 +77,11 @@ def gather_clients(tree: Any, idx: jnp.ndarray) -> Any:
     (weight 0 in reductions, sentinel index at scatter time).  The gather
     is a pure XLA ``gather``: donation-friendly (the (C, ...) operand is
     read once) and the only O(C)-touching op on the sparse round's fast
-    path.
+    path.  Its ops run under the round's ``bafdp.gather`` scope.
     """
-    return jax.tree.map(lambda l: jnp.take(l, idx, axis=0, mode="clip"),
-                        tree)
+    with jax.named_scope("bafdp.gather"):
+        return jax.tree.map(
+            lambda l: jnp.take(l, idx, axis=0, mode="clip"), tree)
 
 
 def scatter_clients(tree: Any, idx: jnp.ndarray, updates: Any) -> Any:
@@ -89,10 +96,12 @@ def scatter_clients(tree: Any, idx: jnp.ndarray, updates: Any) -> Any:
     all duplicate writes carry identical values and the scatter is
     deterministic regardless of XLA's application order (the left-fold
     "last delivery wins" semantics, degenerate because the folds agree).
+    Its ops run under the round's ``bafdp.scatter`` scope.
     """
-    return jax.tree.map(
-        lambda l, u: l.at[idx].set(u.astype(l.dtype), mode="drop"),
-        tree, updates)
+    with jax.named_scope("bafdp.scatter"):
+        return jax.tree.map(
+            lambda l, u: l.at[idx].set(u.astype(l.dtype), mode="drop"),
+            tree, updates)
 
 
 def consensus_gap(state: FedState) -> jnp.ndarray:

@@ -68,6 +68,7 @@ from typing import (
 
 import numpy as np
 
+from repro import tracing
 from repro.core.async_engine import DelayModel, SimResult
 from repro.core.devices import DeviceModel, split_model
 
@@ -671,6 +672,12 @@ def build_schedule(n_rounds: int, delays: "DelayModel | DeviceModel",
     ``stream=True`` draws latency/availability rows one round at a time
     (O(C) live memory — required for million-client fleets, where the
     dense ``(rounds, C)`` matrices of the default path do not fit)."""
+    with tracing.span("schedule.build"):
+        return _build_schedule(n_rounds, delays, trigger, stream)
+
+
+def _build_schedule(n_rounds: int, delays, trigger, stream: bool
+                    ) -> Schedule:
     C = delays.n_clients
     trigger = trigger if trigger is not None else QuorumTrigger()
     if n_rounds == 0:
@@ -851,63 +858,80 @@ class FederatedRun:
         arrivals = self.schedule.arrivals \
             if self.schedule is not None and self.feed_arrivals else None
         for t in range(self.rounds):
-            if rows is not None:
-                row = next(rows)
             if t < self.start:
-                continue                  # replay keeps staleness honest
-            kwargs: Dict[str, Any] = {}
-            if self.round_kwargs is not None:
-                kwargs.update(self.round_kwargs(t))
-            elif rows is not None and sparse:
-                kwargs["idx"], kwargs["stale"], kwargs["weight"] = row
-                if not self.feed_staleness:
-                    # honor the opt-out exactly like the dense branch: the
-                    # round then treats every delivery as fresh (age 0)
-                    del kwargs["stale"]
-                if arrivals is not None:
-                    kwargs["arrivals"] = np.int32(arrivals[t])
-            elif rows is not None:
-                kwargs["act"], kwargs["stale"] = row
-                if not self.feed_staleness:
-                    del kwargs["stale"]
-                if arrivals is not None:
-                    kwargs["arrivals"] = np.int32(arrivals[t])
-            kt = self.key_fn(t) if self.key_fn is not None \
-                else jax.random.fold_in(key, t)
-            if self.ledger is not None:
-                eps_now = getattr(state, "eps", None)
-                if eps_now is None:
-                    raise ValueError(
-                        "ledger= needs a state with a per-client eps "
-                        "vector (FedState); baseline trainer states have "
-                        "no privacy decision variable to account")
-                if sparse:
-                    r_idx, _, r_w = row
-                    ids = np.asarray(r_idx)[np.asarray(r_w) > 0]
-                else:
-                    ids = np.flatnonzero(np.asarray(row[0]))
-                # each delivered message spends the eps the client's local
-                # mechanism runs with THIS round (pre-update state)
-                self.ledger.record(ids, np.asarray(eps_now)[ids])
-            state, m = self.step(state, batch_fn(t), kt, **kwargs)
-            if self.ledger is not None:
-                tot = self.ledger.totals(self.ledger_delta)
-                hist["dp_eps_basic"].append(tot["dp_eps_basic"])
-                hist["dp_eps_adv"].append(tot["dp_eps_adv"])
-            if on_round is not None:
-                on_round(t, state, m)
-            for k in collect:
-                if k in derive:
-                    hist[k].append(derive[k](state, m))
-                elif k in m:
-                    hist[k].append(float(m[k]))
-                elif skip_missing:
-                    # a NaN placeholder keeps history[k] aligned with the
-                    # schedule's round axis — silently appending nothing
-                    # would misalign every loss-vs-wall-clock plot indexed
-                    # against Schedule.times
-                    hist[k].append(float("nan"))
-                else:
-                    raise KeyError(
-                        f"collect key {k!r} not in metrics {sorted(m)}")
+                if rows is not None:
+                    next(rows)            # replay keeps staleness honest
+                continue
+            with tracing.step("fed.round", t):
+                kwargs: Dict[str, Any] = {}
+                if rows is not None:
+                    with tracing.span("fed.schedule_row"):
+                        row = next(rows)
+                if self.round_kwargs is not None:
+                    kwargs.update(self.round_kwargs(t))
+                elif rows is not None and sparse:
+                    kwargs["idx"], kwargs["stale"], kwargs["weight"] = row
+                    if not self.feed_staleness:
+                        # honor the opt-out exactly like the dense branch:
+                        # the round then treats every delivery as fresh
+                        del kwargs["stale"]
+                    if arrivals is not None:
+                        kwargs["arrivals"] = np.int32(arrivals[t])
+                elif rows is not None:
+                    kwargs["act"], kwargs["stale"] = row
+                    if not self.feed_staleness:
+                        del kwargs["stale"]
+                    if arrivals is not None:
+                        kwargs["arrivals"] = np.int32(arrivals[t])
+                with tracing.span("fed.key"):
+                    kt = self.key_fn(t) if self.key_fn is not None \
+                        else jax.random.fold_in(key, t)
+                if self.ledger is not None:
+                    with tracing.span("fed.ledger"):
+                        eps_now = getattr(state, "eps", None)
+                        if eps_now is None:
+                            raise ValueError(
+                                "ledger= needs a state with a per-client "
+                                "eps vector (FedState); baseline trainer "
+                                "states have no privacy decision variable "
+                                "to account")
+                        if sparse:
+                            r_idx, _, r_w = row
+                            ids = np.asarray(r_idx)[np.asarray(r_w) > 0]
+                        else:
+                            ids = np.flatnonzero(np.asarray(row[0]))
+                        # each delivered message spends the eps the
+                        # client's local mechanism runs with THIS round
+                        # (pre-update state)
+                        self.ledger.record(ids, np.asarray(eps_now)[ids])
+                with tracing.span("fed.batch"):
+                    batch = batch_fn(t)
+                with tracing.span("fed.dispatch"):
+                    state, m = self.step(state, batch, kt, **kwargs)
+                if self.ledger is not None:
+                    with tracing.span("fed.ledger"):
+                        tot = self.ledger.totals(self.ledger_delta)
+                        hist["dp_eps_basic"].append(tot["dp_eps_basic"])
+                        hist["dp_eps_adv"].append(tot["dp_eps_adv"])
+                if on_round is not None:
+                    with tracing.span("fed.hook"):
+                        on_round(t, state, m)
+                if collect:
+                    with tracing.span("fed.collect"):
+                        for k in collect:
+                            if k in derive:
+                                hist[k].append(derive[k](state, m))
+                            elif k in m:
+                                hist[k].append(float(m[k]))
+                            elif skip_missing:
+                                # a NaN placeholder keeps history[k] aligned
+                                # with the schedule's round axis — silently
+                                # appending nothing would misalign every
+                                # loss-vs-wall-clock plot indexed against
+                                # Schedule.times
+                                hist[k].append(float("nan"))
+                            else:
+                                raise KeyError(
+                                    f"collect key {k!r} not in metrics "
+                                    f"{sorted(m)}")
         return state, hist

@@ -22,6 +22,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro import tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class TrafficSpec:
@@ -52,6 +54,12 @@ def make_dataset(name: str, n_clients: int, seed: int = 0
     text covariates: tweet count, active users, news count, geo activity.
     meta: one-hot day-of-week (7) + holiday flag + hour-of-day (normalized).
     """
+    with tracing.span("data.make_dataset"):
+        return _make_dataset(name, n_clients, seed)
+
+
+def _make_dataset(name: str, n_clients: int, seed: int
+                  ) -> Dict[str, np.ndarray]:
     spec = DATASETS[name]
     # stable per-dataset offset (Python's str hash is salted per process —
     # using it made every run see different data)

@@ -10,6 +10,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.configs.forecast import ForecastConfig
 
 
@@ -43,6 +44,12 @@ def build_windows(data: Dict[str, np.ndarray], cfg: ForecastConfig,
     train/test: {"x": (C, N, d_x), "y": (C, N, H)}; scalers: per-client
     FeatureScaler fit on the train span of the raw traffic (so RMSE/MAE can
     be reported in raw units like Table I)."""
+    with tracing.span("data.build_windows"):
+        return _build_windows(data, cfg, test_days)
+
+
+def _build_windows(data: Dict[str, np.ndarray], cfg: ForecastConfig,
+                   test_days: int):
     traffic, text, meta = data["traffic"], data["text"], data["meta"]
     C, T = traffic.shape
     cl, pl_, H = cfg.closeness_len, cfg.period_len, cfg.horizon
