@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.configs import FedConfig, ForecastConfig, MLP_H1, MLP_H24
 from repro.configs.forecast import ForecastConfig as FC
 from repro.core import bafdp, init_fed_state
@@ -21,7 +22,7 @@ from repro.core.schedule import FederatedRun, Schedule
 from repro.core.privacy import gaussian_c3, perturb_inputs
 from repro.core.trainers import BaselineTrainer
 from repro.data import build_windows, make_dataset
-from repro.data.windowing import client_batches, rmse_mae
+from repro.data.windowing import client_batches, rmse_mae, stage_rows
 from repro.models.forecasting import (apply_forecaster, init_forecaster,
                                       mse_loss)
 
@@ -162,6 +163,12 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
     *admission* ages as the staleness input.  Needs a ``schedule=``;
     ``fed.consensus_scope`` is promoted to ``"active"`` automatically
     (the sparse path cannot consume inactive clients' frozen messages).
+    Its batches are staged on the host for the round's delivered rows
+    only (``windowing.stage_rows``: the schedule's winners in admission
+    order, padded to ``schedule.s_max`` with the sentinel, the ``idx``
+    row the round is fed) and handed over pre-gathered, ``(S_max, b,
+    ...)``, under the span ``data.stage_rows`` (count ``rows``).  The
+    dense path stages every client's batch (``client_batches``).
 
     ``ledger`` (a :class:`repro.core.privacy.EpsLedger`) turns on
     per-DELIVERY privacy accounting: every schedule row delivery charges
@@ -192,16 +199,28 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
                                           fed.eps_min), y, cfg)
 
     state = init_fed_state(key, lambda k: init_forecaster(k, cfg), fed)
-    round_fn = bafdp.bafdp_round_sparse if round_impl == "sparse" \
-        else bafdp.bafdp_round
+    sparse = round_impl == "sparse"
+    if sparse:              # batch_fn stages the delivered rows only
+        round_fn = functools.partial(bafdp.bafdp_round_sparse,
+                                     batch_gathered=True)
+    else:
+        round_fn = bafdp.bafdp_round
     step = jax.jit(functools.partial(
         round_fn, local_loss=local_loss, fed=fed, c3=c3,
         n_samples=train["x"].shape[1], d_dim=cfg.d_x + cfg.d_y,
         byz_mask=byz_mask(fed.n_clients, fed.n_byzantine)))
     rng = np.random.RandomState(seed)
+    s_max = schedule.s_max if sparse else None
 
     def batch_fn(t):
-        x, y = client_batches(rng, train, BATCH)
+        if sparse:
+            ids = np.full(s_max, fed.n_clients, np.int64)
+            won = schedule.round_winners(t)
+            ids[:won.size] = won
+            with tracing.span("data.stage_rows", rows=s_max):
+                x, y = stage_rows(rng, train, BATCH, ids)
+        else:
+            x, y = client_batches(rng, train, BATCH)
         return jnp.asarray(x), jnp.asarray(y)
 
     # fedbuff_lr_norm needs the schedule's realized per-round K: feed it

@@ -53,7 +53,7 @@ from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import FedConfig  # noqa: E402
 from repro.core.async_engine import DelayModel  # noqa: E402
 from repro.core.schedule import QuorumTrigger, build_schedule  # noqa: E402
-from repro.data.windowing import client_batches  # noqa: E402
+from repro.data.windowing import client_batches, stage_rows  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 
 KERNEL_CLIENTS = (10_000, 16_575)   # Milano; Milano with Trentino
@@ -145,12 +145,15 @@ def train_phase(name: str, fed: FedConfig, rounds: int, seed: int,
     loss = hist["loss"][-1]
 
     # lower the round that ran, on arguments of the shapes it ran with
-    batch = tuple(jnp.asarray(a) for a in client_batches(
-        np.random.RandomState(seed), train, BATCH))
+    rng = np.random.RandomState(seed)
     rkw = {}
     if kw.get("round_impl") == "sparse":
         rkw = dict(zip(("idx", "stale", "weight"),
                        next(kw["schedule"].padded_rows())))
+        batch = stage_rows(rng, train, BATCH, rkw["idx"])
+    else:
+        batch = client_batches(rng, train, BATCH)
+    batch = tuple(jnp.asarray(a) for a in batch)
     kernel = has_kernel(hist["round_fn"].lower(
         state, batch, jax.random.PRNGKey(seed), **rkw))
     ok = kernel and math.isfinite(loss) and math.isfinite(rmse)

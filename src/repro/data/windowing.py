@@ -95,11 +95,33 @@ def _build_windows(data: Dict[str, np.ndarray], cfg: ForecastConfig,
 
 def client_batches(rng: np.random.RandomState, train: Dict[str, np.ndarray],
                    batch: int) -> Tuple[np.ndarray, np.ndarray]:
-    """One round's per-client minibatch: returns x (C, b, d_x), y (C, b, H)."""
+    """One round's per-client minibatch: returns x (C, b, d_x), y (C, b, H).
+
+    Every client's rows, for the rounds that take a batch per client (the
+    dense BAFDP round and the baselines); the sparse round is handed only
+    its delivered rows by :func:`stage_rows`, of which this is the case
+    ``ids = arange(C)``, with the same draw from ``rng``."""
+    return stage_rows(rng, train, batch, np.arange(train["x"].shape[0]))
+
+
+def stage_rows(rng: np.random.RandomState, train: Dict[str, np.ndarray],
+               batch: int, ids: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """One round's minibatch rows for the deliveries ``ids``: returns
+    x (len(ids), b, d_x), y (len(ids), b, H).
+
+    The draw is ``rng.randint(0, N, size=(C, batch))`` for all C clients,
+    whatever ``ids`` holds, so the stream of draws is that of
+    :func:`client_batches`; only the rows of ``ids`` are then gathered, in
+    one take over the (C·N, d) view of each array.  An id of C or more
+    (the padding sentinel of ``Schedule.padded_rows``) takes client C-1's
+    rows, as the sparse round clips it; a repeated id takes its client's
+    one draw again."""
     C, N = train["x"].shape[:2]
-    idx = rng.randint(0, N, size=(C, batch))
-    x = np.take_along_axis(train["x"], idx[:, :, None], axis=1)
-    y = np.take_along_axis(train["y"], idx[:, :, None], axis=1)
+    draw = rng.randint(0, N, size=(C, batch))
+    gid = np.minimum(np.asarray(ids, np.intp), C - 1)
+    flat = gid[:, None] * N + draw[gid]                 # (len(ids), b)
+    x = train["x"].reshape(C * N, -1).take(flat, axis=0)
+    y = train["y"].reshape(C * N, -1).take(flat, axis=0)
     return x, y
 
 
