@@ -147,6 +147,13 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
     jitted round that ran (lower it to see what it compiled to);
     ``on_round(t, state, metrics)`` is :meth:`FederatedRun.run`'s hook.
 
+    The round donates its state (``donate_argnums=0``): each round
+    updates the one resident copy of the fleet in place, and the state it
+    was handed is consumed.  So the state given to ``on_round`` is valid
+    only until the next round is dispatched; copy what must outlive it to
+    the host inside the hook.  The returned state is the last round's and
+    stays valid; running ``round_fn`` on it consumes it.
+
     ``schedule`` (a sparse :class:`repro.core.schedule.Schedule`, e.g.
     from ``build_schedule``) feeds the external event-driven schedule —
     per-round active masks AND consumption-age staleness vectors — into
@@ -198,7 +205,6 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
         return mse_loss(p, perturb_inputs(k, x, eps, input_sigma,
                                           fed.eps_min), y, cfg)
 
-    state = init_fed_state(key, lambda k: init_forecaster(k, cfg), fed)
     sparse = round_impl == "sparse"
     if sparse:              # batch_fn stages the delivered rows only
         round_fn = functools.partial(bafdp.bafdp_round_sparse,
@@ -208,7 +214,8 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
     step = jax.jit(functools.partial(
         round_fn, local_loss=local_loss, fed=fed, c3=c3,
         n_samples=train["x"].shape[1], d_dim=cfg.d_x + cfg.d_y,
-        byz_mask=byz_mask(fed.n_clients, fed.n_byzantine)))
+        byz_mask=byz_mask(fed.n_clients, fed.n_byzantine)),
+        donate_argnums=0)
     rng = np.random.RandomState(seed)
     s_max = schedule.s_max if sparse else None
 
@@ -236,8 +243,10 @@ def train_bafdp(dataset: str, horizon: int, fed: FedConfig,
         round_impl=round_impl, ledger=ledger, ledger_delta=fed.dp_delta,
         round_kwargs=_legacy_round_kwargs(schedule, active_masks, staleness,
                                           rounds, fed.n_clients))
+    # no name holds the initial state: the first round consumes it
     state, hist = run.run(
-        state, batch_fn, key, collect=collect, on_round=on_round,
+        init_fed_state(key, lambda k: init_forecaster(k, cfg), fed),
+        batch_fn, key, collect=collect, on_round=on_round,
         derive={
             "eps_all": lambda s, m: np.asarray(s.eps).copy(),
             "rmse": lambda s, m: eval_fed_state(s, cfg, test, scalers)[0],

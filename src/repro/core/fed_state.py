@@ -88,8 +88,9 @@ def scatter_clients(tree: Any, idx: jnp.ndarray, updates: Any) -> Any:
     """Scatter an (S, ...) block of updated rows back into the (C, ...)
     leaves.  Out-of-range indices (the padding sentinel ``C``) are
     dropped, so padded rows never write.  Updates are cast to each leaf's
-    dtype (the round computes in f32).  With XLA donation the scatter
-    updates the resident stack in place — no (C, ...) copy.
+    dtype (the round computes in f32).  ``benchmarks.common.train_bafdp``
+    donates the state to its jitted round, so the scatter updates the
+    resident stack in place — no (C, ...) copy.
 
     Duplicate in-bounds indices (FedBuff double deliveries) are allowed:
     the round computes every occurrence from the same pre-round state, so

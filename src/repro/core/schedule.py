@@ -797,7 +797,12 @@ class FederatedRun:
         ``collect`` (``derive[k](state, m)`` when supplied, else
         ``float(metrics[k])``).  With ``skip_missing=True`` a key absent
         from a round's metrics contributes ``float("nan")`` — every
-        history list stays aligned with the schedule's round axis."""
+        history list stays aligned with the schedule's round axis.
+
+        ``step`` may donate its state (``train_bafdp``'s does): the loop
+        reads a state (the ledger's ``eps``, ``on_round``, ``derive``)
+        only before handing it to the next step, and a hook that keeps
+        part of it past that copies it to the host."""
         if self.round_impl not in ("dense", "sparse"):
             raise ValueError(
                 f"unknown round_impl: {self.round_impl!r} "
