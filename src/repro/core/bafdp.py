@@ -674,7 +674,11 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
     and the gathered call share one code path, so XLA cannot compile
     their per-row math differently the way two structurally distinct
     programs do.  Consequently the order of ``idx`` entries never
-    matters.
+    matters.  The bit-parity is a property of the XLA path (the CPU and
+    every ``impl="xla"`` fold).  On the TPU the fold's sign sum runs in
+    the tiled Pallas kernel and its dual mean as one vectorised reduction
+    (``kernels/ops.dual_mean``), whose additions are grouped by tile and
+    lane, so there the dense and sparse rounds agree to f32 tolerance.
 
     FedBuff duplicate deliveries (the same client id twice in ``idx``)
     follow a left-fold semantics: every delivery enters the Eq. (20)
@@ -922,8 +926,9 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
 
     def z_step(z_l, w_l, phi_l):
         zf = z_l.ravel()
-        # dual term over the consumed messages: sum_j w_j phi_j / C, the
-        # same left-fold the active-scope dense round runs over C rows.
+        # dual term over the consumed messages: sum_j w_j phi_j / C — the
+        # left-fold the active-scope dense round runs over C rows on the
+        # XLA path, one vectorised reduction on the TPU (ops.dual_mean).
         # dual_message="int8" folds the DECODED absmax-quantized uploads
         # (row-local quantizer — dense<->sparse parity is preserved).
         if dual_message == "int8":
@@ -933,8 +938,7 @@ def bafdp_round_sparse(state: FedState, batch: Any, key, *,
             phi_m = kref.fold_weighted_rowsum_stream(
                 phi_l.reshape(S, -1), w_row, chunk) / C
         else:
-            phi_m = kref.fold_weighted_rowsum(phi_l.reshape(S, -1),
-                                              w_row) / C
+            phi_m = kops.dual_mean(phi_l, w_row, C)
         z_upd = kops.sign_consensus(zf, w_l.reshape(S, -1), phi_m, s_w,
                                     fed.psi, fed.alpha_z,
                                     message=sign_message, n_total=C,

@@ -54,9 +54,11 @@ def sign_consensus(z, W, phi_mean, weights, psi: float, alpha_z: float,
     On the XLA path the reduction then runs as an order-canonical
     left-fold over rows (``ref.sign_agg_fold_ref``), which is what makes
     the masked dense round and the gathered sparse round bit-identical;
-    the fused TPU kernels keep their tiled reduction and agree to float
-    tolerance.  Requires ``weights`` (the padding/activity mask at
-    minimum).
+    that bit-parity is a property of the XLA path only.  On the TPU the
+    fused kernels keep their tiled reduction and the dual mean beside
+    them is one vectorised reduction (:func:`dual_mean`), so the whole
+    fold agrees to f32 tolerance.  Requires ``weights`` (the
+    padding/activity mask at minimum).
 
     ``streaming=True`` consumes the fold as an online reduction over
     arrival-event chunks of ``chunk_size`` rows
@@ -110,6 +112,34 @@ def sign_consensus(z, W, phi_mean, weights, psi: float, alpha_z: float,
         return sign_agg(z, W, phi_mean, psi, alpha_z, impl=impl)
     return sign_agg_weighted(z, W, phi_mean, weights, psi, alpha_z,
                              impl=impl)
+
+
+@functools.partial(jax.jit, static_argnames=("n_total", "impl"))
+def dual_mean(phi_block, weights, n_total: int, impl: str = "auto"):
+    """The Eq. (22) dual term of the fold: ``sum_j weights[j] * phi_j /
+    n_total`` over the client axis of one (S, ...) leaf, raveled to (D,).
+
+    On the XLA path it is ``ref.fold_weighted_rowsum`` — the row-order
+    left-fold whose bits the masked dense round and the gathered sparse
+    round share.  On the TPU paths (``"pallas"``, ``"interpret"``) it is
+    one f32 multiply-and-sum over axis 0 of the leaf in its own shape: a
+    VPU reduction that reads the block once (no matmul, which would round
+    its inputs to bfloat16 at default precision, and no ``while`` loop of
+    S trips).  Its additions are grouped by lane, so it agrees with the
+    left-fold to f32 tolerance, as the tiled Pallas sign sum beside it
+    does.
+    """
+    impl = _resolve(impl)
+    S = phi_block.shape[0]
+    if impl == "xla":
+        return ref.fold_weighted_rowsum(phi_block.reshape(S, -1),
+                                        weights) / n_total
+    # reduce the leaf in its own shape: a reshape to (S, D) first can
+    # cost a layout copy of the whole block on the TPU
+    w = weights.astype(jnp.float32).reshape(
+        (S,) + (1,) * (phi_block.ndim - 1))
+    wsum = jnp.sum(w * phi_block.astype(jnp.float32), axis=0)
+    return wsum.ravel() / n_total
 
 
 @functools.partial(jax.jit, static_argnames=("psi", "alpha_z", "impl"))

@@ -54,8 +54,12 @@ def fold_weighted_rowsum(X: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     any accumulator this fold can produce), so folding C rows with S
     nonzero weights equals folding just those S rows in the same relative
     order.  This is the reduction the ``consensus_scope="active"`` dense
-    round and the gathered sparse round share — the dense<->sparse
-    bit-parity contract rests on it.
+    round and the gathered sparse round share on the XLA path — the
+    dense<->sparse bit-parity contract rests on it.  On the TPU the
+    round's dual mean is one vectorised reduction instead
+    (``ops.dual_mean``): on a TPU v5e the loop costs about 0.7 us a trip
+    whatever the row's width, and the parity it buys holds only on the
+    XLA path.
     """
     Xf = X.astype(jnp.float32)
     wf = weights.astype(jnp.float32)

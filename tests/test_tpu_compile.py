@@ -63,3 +63,18 @@ def test_sign_consensus_compiles_for_v5e(one_chip, C, flavour):
         message=message, impl="pallas", n_total=C if padded else None)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [(10_000, 128, 128), (6_000, 64, 24)],
+                         ids=str)
+def test_dual_mean_compiles_for_v5e_as_one_reduction(one_chip, shape):
+    """The fold's dual mean on the TPU: no loop over the client rows, and
+    no Pallas call (``fold_roofline`` counts each custom call of the
+    round as one sign-fold call)."""
+    lowered = ops.dual_mean.lower(
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct(shape[:1], jnp.float32, sharding=one_chip),
+        shape[0], impl="pallas")
+    text = lowered.compile().as_text()
+    assert "while" not in text
+    assert "tpu_custom_call" not in text
